@@ -1,4 +1,4 @@
-"""Error taxonomy spanning every layer, with HTTP status mapping.
+"""Error hierarchy spanning every layer, with HTTP status mapping.
 
 Behavioral parity with reference ``crates/core/src/error.rs:6-141``: seven
 error families (server, API, validation, queue, batcher, cache, worker,

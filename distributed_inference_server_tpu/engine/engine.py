@@ -465,12 +465,17 @@ class LLMEngine:
         draft_params: Optional[llama.Params] = None,
         draft_cfg: Optional[ModelConfig] = None,
         spec: Optional[SpecConfig] = None,
+        device=None,
     ):
         """``mesh``: optional ``jax.sharding.Mesh`` (parallel/mesh.py) for
         intra-replica tensor parallelism — weights and the paged KV pool are
         sharded over the ``tensor`` axis (parallel/tp.py layout) and every
         jitted step runs SPMD with XLA-inserted ICI collectives. Without a
-        mesh, single-device execution (the reference's worker model).
+        mesh, single-device execution (the reference's worker model) — on
+        ``device`` when given: weights and pools are COMMITTED there, so
+        every jitted step follows them and N one-chip replicas in one
+        process use N chips (uncommitted, they would all land on
+        ``jax.devices()[0]``).
 
         ``draft_params``/``draft_cfg``: optional draft model enabling
         speculative decoding inside the continuous-batching step (Req 12,
@@ -501,12 +506,11 @@ class LLMEngine:
             # (value validation itself lives in PagedKVState.create)
             if self.ecfg.attention_impl == "pallas":
                 raise ValueError(
-                    "kv_quant='int8' serves on the XLA attention path "
-                    "for now: the int8-pool decode kernel exists "
-                    "(ops/pallas/paged_attention.py) but is not wired "
-                    "into serving until proven on real silicon "
-                    "(tools/kernel_probe.py KP_KV_QUANT=1), and the "
-                    "prefill kernel has no int8 variant"
+                    "kv_quant='int8' serves on the XLA attention path: "
+                    "Mosaic rejects the int8-pool decode kernel "
+                    "(ops/pallas/paged_attention.py; tools/"
+                    "kernel_probe.py) and the prefill kernel has no "
+                    "int8 variant"
                 )
             # stage axes: QuantPool pools thread through pp_paged_forward
             # as pytrees with per-member stage specs (parallel/pp.py);
@@ -556,6 +560,14 @@ class LLMEngine:
 
         self.state = PagedKVState.create(cfg, self.pcfg, dtype=dtype,
                                          kv_quant=kvq)
+        if mesh is not None and device is not None:
+            raise ValueError("pass a mesh or a device, not both")
+        if device is not None:
+            # (a missing draft is None, an empty pytree: passes through)
+            self.params, self.draft_params = jax.device_put(
+                (self.params, self.draft_params), device)
+            for st in filter(None, (self.state, self.draft_state)):
+                st.k, st.v = jax.device_put((st.k, st.v), device)
         if mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -721,6 +733,10 @@ class LLMEngine:
         # jit caches
         # "auto" probe result: (decode_impl, prefill_impl) once resolved
         self._auto_impl: Optional[Tuple[str, str]] = None
+        # kernel name -> first line of Mosaic's message, for every kernel
+        # the "auto" probes rejected (placement() reports them; a
+        # rejection must be visible, not a quiet XLA server)
+        self._probe_rejected: Dict[str, str] = {}
         # experimental int8-pool Pallas decode opt-in, captured ONCE at
         # construction: re-reading the env per resolution call could flip
         # the attention impl mid-serving after blocks were already built
@@ -2363,10 +2379,11 @@ class LLMEngine:
             ).compile()
             return True
         except Exception as e:  # Mosaic rejection or backend failure
+            first = str(e).split("\n")[0]
+            self._probe_rejected["ragged"] = first
             logger.warning(
                 "Pallas ragged mixed-batch kernel unavailable for this "
-                "geometry (mixed step -> xla ragged path): %s",
-                str(e).split("\n")[0],
+                "geometry (mixed step -> xla ragged path): %s", first,
             )
             return False
 
@@ -2992,29 +3009,44 @@ class LLMEngine:
             if jax.default_backend() != "tpu":
                 self._auto_impl = ("xla", "xla")
             else:
+                # each kernel serves where Mosaic accepts it. Prefill is
+                # not optional at serving widths: the XLA form gathers a
+                # dense [B, S_max] window and materializes f32 scores
+                # [B, H, T, S_max] — 2 x 8 GB at the default geometry
+                # (16 rows x 512 tokens x 8192 slots), which a 16 GB chip
+                # cannot compile — while the kernel reads only the pages
+                # a query tile can causally see.
                 ok_decode, ok_prefill = self._probe_pallas()
-                # prefill DEMOTED to opt-in (VERDICT r4 #3 "win or
-                # demote"): Mosaic acceptance proves the kernel compiles,
-                # not that it's fast, and the only silicon datapoint has
-                # the chunked-prefill kernel at 0.66x XLA blocking at
-                # serving geometry (BENCH_NOTES_r04.md §1). Until the
-                # queued long-context crossover sweep produces >= 2
-                # geometries where it wins, auto serves prefill on XLA;
-                # DIS_TPU_PALLAS_PREFILL=1 re-enables it for sweeps (an
-                # explicit attention_impl='pallas' pin always did).
-                # Decode keeps pallas-if-compiles: end-to-end parity at
-                # short context (2,049 vs 2,120 tok/s) with strictly
-                # less DMA at long context (reads only valid pages vs
-                # the XLA path's bucketed gather).
-                want_prefill = (
-                    ok_prefill
-                    and os.environ.get("DIS_TPU_PALLAS_PREFILL") == "1"
-                )
                 self._auto_impl = (
                     "pallas" if ok_decode else "xla",
-                    "pallas" if want_prefill else "xla",
+                    "pallas" if ok_prefill else "xla",
                 )
         return self._auto_impl
+
+    def placement(self) -> Dict[str, object]:
+        """Where this replica lives and what serves its attention: the
+        devices holding its KV pool (with the bytes each has in use,
+        where the backend reports them), the resolved (decode, prefill)
+        attention pair, and Mosaic's first line for any kernel the
+        "auto" probe rejected. ``/health`` reports it per engine. Reads
+        cached state only — the pair was resolved at construction."""
+        k = self.state.k
+        # .sharding, not .devices(): the pool is donated into every step,
+        # and a status read from another thread may catch the stale handle
+        devs = sorted(
+            (k.data if isinstance(k, QuantPool) else k).sharding.device_set,
+            key=lambda d: d.id,
+        )
+        impl = self._resolved_impl()
+        decode, prefill = (impl, impl) if isinstance(impl, str) else impl
+        return {
+            "device_ids": [d.id for d in devs],
+            "device_bytes_in_use": [
+                (d.memory_stats() or {}).get("bytes_in_use") for d in devs
+            ],
+            "attention": {"decode": decode, "prefill": prefill},
+            "attention_rejected": dict(self._probe_rejected),
+        }
 
     def _probe_pallas(self) -> Tuple[bool, bool]:
         """AOT-compile the Pallas paged-attention kernels (decode, chunked
@@ -3058,10 +3090,11 @@ class LLMEngine:
                 lower_thunk().compile()
                 return True
             except Exception as e:  # Mosaic rejection or backend failure
+                first = str(e).split("\n")[0]
+                self._probe_rejected[name] = first
                 logger.warning(
                     "Pallas %s kernel unavailable for this geometry "
-                    "(auto -> xla gather path): %s",
-                    name, str(e).split("\n")[0],
+                    "(auto -> xla gather path): %s", name, first,
                 )
                 return False
 

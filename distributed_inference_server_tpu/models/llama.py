@@ -564,10 +564,9 @@ def shard_ragged_attend(fn, mesh):
     operand replicated (the mixed step does not shard rows — the engine
     rejects mixed_step_tokens under a data axis). Shared by the probe
     and the serving path like ``shard_pallas_attend``."""
-    from distributed_inference_server_tpu.utils.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -604,7 +603,6 @@ def shard_pallas_attend(fn, mesh, decode_step: bool,
     probe lowers the SAME shard_map program the serving path launches —
     a standalone kernel lowering could in principle pass Mosaic while the
     sharded lowering fails (or vice versa)."""
-    from distributed_inference_server_tpu.utils.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     from distributed_inference_server_tpu.ops.quant import QuantPool
@@ -626,7 +624,7 @@ def shard_pallas_attend(fn, mesh, decode_step: bool,
     if not decode_step:
         in_specs.append(P("data"))  # q_start [B] row starts
     in_specs.append(P())  # this layer's sliding window (replicated scalar)
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=tuple(in_specs),

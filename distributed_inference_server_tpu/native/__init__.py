@@ -39,10 +39,13 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         if _build_failed:
             return None
-        # always run make: its dependency tracking rebuilds a stale .so
-        # after source edits (a no-op when up to date). Running under
-        # _lock is deliberate — concurrent first callers must wait for
-        # the one build, not race it.
+        # always run make: its dependency tracking rebuilds the .so from
+        # the committed sources after any edit (a no-op when up to date),
+        # so a library that loads is never older than its sources. A
+        # failed build means the Python tier serves — never a binary left
+        # on disk by some earlier build. Running under _lock is
+        # deliberate — concurrent first callers must wait for the one
+        # build, not race it.
         try:
             subprocess.run(  # distlint: ignore[DL003]
                 ["make", "-C", _DIR],
@@ -50,20 +53,11 @@ def _load() -> Optional[ctypes.CDLL]:
                 capture_output=True,
                 timeout=120,
             )
-        except Exception as e:
-            if not os.path.exists(_LIB_PATH):
-                logger.info("native build failed (%s); Python tier only", e)
-                _build_failed = True
-                return None
-            logger.info("native rebuild failed (%s); using the existing "
-                        ".so", e)
-        try:
             lib = ctypes.CDLL(_LIB_PATH)
             _declare(lib)
-        except (OSError, AttributeError):
-            # unloadable OR stale .so missing newer symbols (make failed
-            # after a source update): fall back to the Python tier rather
-            # than crash every native-capable caller
+        except (OSError, subprocess.SubprocessError, AttributeError) as e:
+            logger.warning("native build/load failed (%s); Python tier "
+                           "only", e)
             _build_failed = True
             return None
         _lib = lib
@@ -137,6 +131,12 @@ def _declare(lib: ctypes.CDLL) -> None:
 def available() -> bool:
     """True when the native library is built (builds on first call)."""
     return _load() is not None
+
+
+def loaded() -> bool:
+    """True when the native library is serving in this process. Unlike
+    ``available()`` it never triggers a build — a status read."""
+    return _lib is not None
 
 
 def _i32arr(vals: Sequence[int]):

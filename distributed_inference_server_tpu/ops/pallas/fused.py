@@ -38,11 +38,12 @@ from jax.experimental.pallas import tpu as pltpu
 def fused_mode() -> str | None:
     """Trace-time switch for the opt-in fused kernels.
 
-    DIS_TPU_PALLAS_FUSED=1        -> "compiled" on a SINGLE-device TPU
+    DIS_TPU_PALLAS_FUSED=1        -> "compiled"; needs a SINGLE-device TPU
                                       backend (GSPMD cannot partition an
-                                      opaque pallas_call, so the flag is
-                                      ignored — XLA path — the moment
-                                      more than one device is visible)
+                                      opaque pallas_call) and raises
+                                      anywhere else — asking for the
+                                      kernels and silently serving the
+                                      XLA path would hide the device
     DIS_TPU_PALLAS_FUSED=interpret -> "interpret" on any backend (tests:
                                       exercises the exact dispatch path
                                       off-TPU)
@@ -51,11 +52,13 @@ def fused_mode() -> str | None:
     v = os.environ.get("DIS_TPU_PALLAS_FUSED", "0")
     if v == "interpret":
         return "interpret"
-    if (
-        v == "1"
-        and jax.default_backend() == "tpu"
-        and jax.device_count() == 1
-    ):
+    if v == "1":
+        if jax.default_backend() != "tpu" or jax.device_count() != 1:
+            raise RuntimeError(
+                "DIS_TPU_PALLAS_FUSED=1 needs a single-device TPU backend; "
+                f"found {jax.device_count()} {jax.default_backend()} "
+                "device(s). Unset it to serve on the XLA fused path."
+            )
         return "compiled"
     return None
 
@@ -211,8 +214,11 @@ def _q4_matmul_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, n_k: int):
     st = s_ref[...].astype(jnp.float32)  # [BK//G, BN]
     groups, BN = st.shape
     halfk = packed.shape[0]
-    low = (packed & 0xF).astype(jnp.int8)
-    high = (packed >> 4).astype(jnp.int8)
+    # nibble arithmetic in int32: Mosaic has no 8-bit vector shift
+    # ("failed to legalize operation 'arith.shrui'" on vector<..xi8>)
+    wide = packed.astype(jnp.int32)
+    low = wide & 0xF
+    high = wide >> 4
     low = jnp.where(low > 7, low - 16, low)
     high = jnp.where(high > 7, high - 16, high)
     # interleave to k order: row 2i = low_i, 2i+1 = high_i (quant.py pack)

@@ -52,7 +52,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from distributed_inference_server_tpu.utils.compat import tpu_compiler_params
 
 _NEG_INF = -1e30
 _LANES = 128  # VPU lane width; scratch statistics are broadcast across lanes
@@ -516,7 +515,7 @@ def paged_attention_prefill(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVc, T * C * G, CD), q.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -566,12 +565,14 @@ def paged_attention_decode(
         the kernel then DMAs HALF the attention bytes and folds the
         scales into the score/probability matrices on the fly.
         CAVEAT (quantized mode): the scale VMEM scratch and DMA tiles are
-        [page_size, KV] with KV typically far below the 128-lane Mosaic
-        tile — this lane width is the expected Mosaic rejection point on
-        real silicon (all CI runs use interpret=True). Serving gates the
-        kernel behind DIS_TPU_KV_QUANT_PALLAS=1 plus an AOT probe with
-        XLA fallback; land the KP_KV_QUANT=1 silicon probe before
-        widening the opt-in.
+        [page_size, KV] with KV far below the 128-lane Mosaic tile, and
+        Mosaic REJECTS the kernel on the chip for it ("Slice shape along
+        dimension 2 must be aligned to tiling (128), but is 8" at KV=8,
+        D=64 and D=128, jax 0.9.0 on a v5e; tools/kernel_probe.py). It
+        runs in interpret mode only. Serving gates it behind
+        DIS_TPU_KV_QUANT_PALLAS=1 plus the AOT probe, which today always
+        resolves to the XLA path; the repair is a scale layout with the
+        token axis in lanes (ROADMAP S6).
       page_tables: [B, P] page ids per row (entries past the row's last
         page may be any value; they are clamped to the pool and masked).
       kv_valid_len: [B] valid tokens per row, INCLUDING the just-written
@@ -671,7 +672,7 @@ def paged_attention_decode(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, CD), q.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # rows are independent — scratch state is reset per grid step
             # — so let megacore split the batch
             dimension_semantics=("parallel",),
@@ -704,8 +705,8 @@ def _ragged_kernel(
     # tensor refs
     qbd_ref,  # [1, 1, R, CD] this (window, head-chunk)'s block-diagonal
     #           query tile; R = TQ*C*G
-    posr_ref,  # [1, R] per-q-row absolute position (token-expanded)
-    rowr_ref,  # [1, R] per-q-row owning batch row (-1 = padding token)
+    posr_ref,  # [1, R, 1] per-q-row absolute position (token-expanded)
+    rowr_ref,  # [1, R, 1] per-q-row owning batch row (-1 = padding token)
     k_hbm,  # [num_pages, page_size, KV*D] full K pool (HBM)
     v_hbm,  # [num_pages, page_size, KV*D] full V pool (HBM)
     out_ref,  # [1, 1, R, CD] (VMEM; revisited by every segment of the window)
@@ -730,16 +731,18 @@ def _ragged_kernel(
     (the first one zero-initializes it). The KV loop covers only the
     segment's row, exactly like the decode/prefill kernels' per-row loop
     — ragged per-row trip counts are the whole point."""
+    c = pl.program_id(0)
     i = pl.program_id(1)
     R, CD = qbd_ref.shape[2], qbd_ref.shape[3]
     PB = pages_per_block
     blk_tokens = PB * page_size
+    lane_lo = c * CD  # this head-chunk's 128-aligned lane window
 
     b = wrow_ref[i]
     bb = jnp.maximum(b, 0)
     valid = jnp.where(b >= 0, valid_ref[bb], 0)
-    pos_r = posr_ref[0].reshape(R, 1)
-    row_r = rowr_ref[0].reshape(R, 1)
+    pos_r = posr_ref[0]  # [R, 1]
+    row_r = rowr_ref[0]
     belongs = (row_r == b) & (b >= 0)
 
     # the segment's query-position span bounds the KV loop: nothing past
@@ -763,10 +766,12 @@ def _ragged_kernel(
             page = tables_ref[bb, jnp.minimum(blk * PB + j,
                                               num_page_slots - 1)]
             pltpu.make_async_copy(
-                k_hbm.at[page], k_buf.at[slot, j], sem_k.at[slot, j]
+                k_hbm.at[page, :, pl.ds(lane_lo, CD)],
+                k_buf.at[slot, j], sem_k.at[slot, j]
             ).start()
             pltpu.make_async_copy(
-                v_hbm.at[page], v_buf.at[slot, j], sem_v.at[slot, j]
+                v_hbm.at[page, :, pl.ds(lane_lo, CD)],
+                v_buf.at[slot, j], sem_v.at[slot, j]
             ).start()
 
     def wait_block(slot, blk):
@@ -774,10 +779,12 @@ def _ragged_kernel(
             page = tables_ref[bb, jnp.minimum(blk * PB + j,
                                               num_page_slots - 1)]
             pltpu.make_async_copy(
-                k_hbm.at[page], k_buf.at[slot, j], sem_k.at[slot, j]
+                k_hbm.at[page, :, pl.ds(lane_lo, CD)],
+                k_buf.at[slot, j], sem_k.at[slot, j]
             ).wait()
             pltpu.make_async_copy(
-                v_hbm.at[page], v_buf.at[slot, j], sem_v.at[slot, j]
+                v_hbm.at[page, :, pl.ds(lane_lo, CD)],
+                v_buf.at[slot, j], sem_v.at[slot, j]
             ).wait()
 
     m0 = jnp.full((R, 1), _NEG_INF, jnp.float32)
@@ -944,13 +951,17 @@ def paged_attention_ragged(
         q.reshape(num_win, TQ, KVc, C, G, D), eye,
     )  # [num_win, TQ, KVc, C, G, C, D]
     qbd = qbd.transpose(0, 2, 1, 3, 4, 5, 6).reshape(num_win, KVc, R, CD)
-    # per-q-row position / owning row (token-expanded to the R axis)
+    # per-q-row position / owning row (token-expanded to the R axis).
+    # Shaped [num_win, R, 1] — a column per window — so each block's last
+    # two dims EQUAL the array's (Mosaic's block-shape rule; a (1, R)
+    # block of a [num_win, R] array is rejected) and the kernel reads the
+    # [R, 1] column it broadcasts against with no lane->sublane reshape
     pos_r = jnp.broadcast_to(
         q_pos.reshape(num_win, TQ, 1), (num_win, TQ, C * G)
-    ).reshape(num_win, R)
+    ).reshape(num_win, R, 1)
     row_r = jnp.broadcast_to(
         tok_row.reshape(num_win, TQ, 1), (num_win, TQ, C * G)
-    ).reshape(num_win, R)
+    ).reshape(num_win, R, 1)
 
     k_pages = pool_k.reshape(num_pages, page_size, KV * D)
     v_pages = pool_v.reshape(num_pages, page_size, KV * D)
@@ -962,10 +973,10 @@ def paged_attention_ragged(
         in_specs=[
             pl.BlockSpec((1, 1, R, CD),
                          lambda c, i, t, vl, wr, ww, wf, w: (ww[i], c, 0, 0)),
-            pl.BlockSpec((1, R),
-                         lambda c, i, t, vl, wr, ww, wf, w: (ww[i], 0)),
-            pl.BlockSpec((1, R),
-                         lambda c, i, t, vl, wr, ww, wf, w: (ww[i], 0)),
+            pl.BlockSpec((1, R, 1),
+                         lambda c, i, t, vl, wr, ww, wf, w: (ww[i], 0, 0)),
+            pl.BlockSpec((1, R, 1),
+                         lambda c, i, t, vl, wr, ww, wf, w: (ww[i], 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
@@ -992,7 +1003,7 @@ def paged_attention_ragged(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_win, KVc, R, CD), q.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # segments of one window REVISIT the same out block (RMW);
             # both axes stay sequential
             dimension_semantics=("arbitrary", "arbitrary"),

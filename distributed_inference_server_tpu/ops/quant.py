@@ -225,9 +225,10 @@ class QuantPool(NamedTuple):
     stacked layers, buffer donation, and device_put thread it like a
     plain array; XLA-gather attention dequantizes after the page-granular
     gather. The Pallas DECODE kernel also accepts it (int8 page DMA with
-    in-kernel scale folding, ops/pallas/paged_attention.py), but serving
-    keeps the XLA path for kv_quant until that variant is proven on real
-    silicon (tools/kernel_probe.py KP_KV_QUANT=1 is the proof step); the
+    in-kernel scale folding, ops/pallas/paged_attention.py) in interpret
+    mode only: Mosaic rejects it on the chip (the [page_size, KV] scale
+    tiles are narrower than the 128-lane tiling; tools/kernel_probe.py,
+    CHANGES.md PR 21), so serving keeps the XLA path for kv_quant. The
     prefill kernel has no int8 variant.
 
     data:  [..., num_slots, KV, D] int8 codes

@@ -21,7 +21,6 @@ tails, ragged batches via kv_valid masks).
 from __future__ import annotations
 
 import jax
-from distributed_inference_server_tpu.utils.compat import axis_size, pcast, shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
@@ -60,7 +59,7 @@ def ring_attention(
     B, Tl, H, D = q.shape
     KV = k.shape[2]
     G = H // KV
-    ring = axis_size(axis_name)
+    ring = lax.axis_size(axis_name)
     scale = 1.0 / (D**0.5)
 
     qg = q.astype(jnp.float32).reshape(B, Tl, KV, G, D)
@@ -126,7 +125,7 @@ def ring_attention(
     except (AttributeError, TypeError):
         vma = ()
     if vma:
-        stats0 = tuple(pcast(x, vma, to="varying") for x in stats0)
+        stats0 = tuple(lax.pcast(x, vma, to="varying") for x in stats0)
     # ring-1 rotate-and-accumulate steps, then a peeled final accumulate —
     # the last rotation's result would be discarded, so don't issue it
     (stats, k_last, v_last, pos_last), _ = lax.scan(
@@ -160,7 +159,7 @@ def ring_attention_sharded(
         P("data", axis_name),
     )
     if sliding_window is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda *a: ring_attention(*a, axis_name=axis_name,
                                       attn_softcap=attn_softcap),
             mesh=mesh,
@@ -169,7 +168,7 @@ def ring_attention_sharded(
             check_vma=False,
         )
         return fn(q, k, v, q_positions, kv_positions)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q, k, v, qp, kp, w: ring_attention(
             q, k, v, qp, kp, axis_name=axis_name, sliding_window=w,
             attn_softcap=attn_softcap,

@@ -20,7 +20,6 @@ counts (GQA keeps its group structure after the scatter).
 from __future__ import annotations
 
 import jax
-from distributed_inference_server_tpu.utils.compat import axis_size, shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
@@ -50,7 +49,7 @@ def ulysses_attention(
 
     Returns [B, Tl, H, D] in q.dtype — attention over the FULL sequence.
     """
-    s = axis_size(axis_name)
+    s = lax.axis_size(axis_name)
     H, KV = q.shape[2], k.shape[2]
     if H % s or KV % s:
         raise ValueError(
@@ -95,7 +94,7 @@ def ulysses_attention_sharded(
         P("data"),
     )
     if sliding_window is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda *a: ulysses_attention(*a, axis_name=axis_name,
                                          attn_softcap=attn_softcap),
             mesh=mesh,
@@ -104,7 +103,7 @@ def ulysses_attention_sharded(
             check_vma=False,
         )
         return fn(q, k, v, q_positions, kv_valid_len)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q, k, v, qp, kv, w: ulysses_attention(
             q, k, v, qp, kv, axis_name=axis_name, sliding_window=w,
             attn_softcap=attn_softcap,

@@ -20,7 +20,6 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-from distributed_inference_server_tpu.utils.compat import pcast, shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -285,16 +284,16 @@ def cp_pp_prefill(
             return state, ck, cv, out
 
         dt = embed.dtype
-        state0 = pcast(
+        state0 = lax.pcast(
             jnp.zeros((B_mb, Tl, cfg.hidden_size), dt), "stage", to="varying"
         )
-        state0 = pcast(state0, "seq", to="varying")
-        out0 = pcast(
+        state0 = lax.pcast(state0, "seq", to="varying")
+        out0 = lax.pcast(
             jnp.zeros((B, Tl, cfg.hidden_size), dt), "stage", to="varying"
         )
-        out0 = pcast(out0, "seq", to="varying")
-        ck0 = pcast(
-            pcast(
+        out0 = lax.pcast(out0, "seq", to="varying")
+        ck0 = lax.pcast(
+            lax.pcast(
                 jnp.zeros((L_stage, B, Tl, cfg.num_kv_heads, cfg.head_dim),
                           dt),
                 "stage", to="varying",
@@ -329,7 +328,7 @@ def cp_pp_prefill(
     unembed = (
         params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         axis_names={"seq", "stage"},  # data/tensor stay GSPMD-managed

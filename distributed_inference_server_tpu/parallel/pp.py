@@ -30,7 +30,6 @@ import functools
 from typing import Optional, Tuple
 
 import jax
-from distributed_inference_server_tpu.utils.compat import pcast, shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
@@ -145,11 +144,11 @@ def pp_forward(
             return state, ck, cv, out
 
         # carries start stage-varying (vma tracking needs the promotion)
-        state0 = pcast(
+        state0 = lax.pcast(
             jnp.zeros((B_mb, T, cfg.hidden_size), embed.dtype),
             "stage", to="varying",
         )
-        out0 = pcast(
+        out0 = lax.pcast(
             jnp.zeros((B, T, cfg.hidden_size), embed.dtype),
             "stage", to="varying",
         )
@@ -170,7 +169,7 @@ def pp_forward(
     unembed = (
         params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         axis_names={"stage"},  # tensor/data stay GSPMD-managed inside
@@ -316,11 +315,11 @@ def pp_paged_forward(
             )
             return state, pk, pv, out
 
-        state0 = pcast(
+        state0 = lax.pcast(
             jnp.zeros((B_mb, T, cfg.hidden_size), embed.dtype),
             "stage", to="varying",
         )
-        out0 = pcast(
+        out0 = lax.pcast(
             jnp.zeros((B, T, cfg.hidden_size), embed.dtype),
             "stage", to="varying",
         )
@@ -350,7 +349,7 @@ def pp_paged_forward(
     pool_spec = (
         QuantPool(P("stage"), P("stage")) if kv_quantized else P("stage")
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         axis_names={"stage"},  # tensor/data stay GSPMD-managed inside

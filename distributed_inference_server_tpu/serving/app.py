@@ -566,13 +566,39 @@ def build_app(
         )
 
     async def health(request: web.Request) -> web.Response:
-        statuses = handler.dispatcher.scheduler.statuses()
+        """Liveness plus the device facts an outside checker cannot get
+        without touching jax itself (chip_smoke.py, whose process must
+        leave the chip to this one): the backend's platform, device kind
+        and count; per LOCAL engine the devices it holds and the
+        attention kernels it resolved to; whether the native C++ tier is
+        loaded; the compile-cache directory."""
+        import jax
+
+        from distributed_inference_server_tpu import native
+
+        scheduler = handler.dispatcher.scheduler
+        statuses = scheduler.statuses()
         healthy = any(s.healthy for s in statuses)
+        # remote (fleet) runners have no placement(): their devices are
+        # another process's to report
+        placements = {
+            r.engine_id: r.placement() for r in scheduler.engines()
+            if hasattr(r, "placement")
+        }
+        devices = jax.devices()
         return web.json_response(
             {
                 "status": "ok" if healthy else "unhealthy",
                 "accepting": handler.dispatcher.is_accepting(),
-                "engines": [s.to_dict() for s in statuses],
+                "platform": devices[0].platform,
+                "device_kind": devices[0].device_kind,
+                "device_count": len(devices),
+                "native_tier": native.loaded(),
+                "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+                "engines": [
+                    {**s.to_dict(), **placements.get(s.engine_id, {})}
+                    for s in statuses
+                ],
             },
             status=200 if healthy else 503,
         )

@@ -46,11 +46,6 @@ _SCHEMA: Dict[str, Dict[str, Any]] = {
         "port": (int, 8000),
         # gRPC transport next to HTTP (serving/grpc_server.py); 0 = off
         "grpc_port": (int, 0),
-        # persistent XLA compilation cache: restarts (and hot-swaps back
-        # to a previously-served model) skip the 20-40s compiles. "" = off
-        "compile_cache_dir": (
-            str, "~/.cache/distributed-inference-server-tpu/xla"
-        ),
         "num_engines": (int, 1),
         # disaggregated prefill/decode serving (serving/disagg.py;
         # docs/DISAGG.md): comma-separated role per replica, e.g.
@@ -97,7 +92,7 @@ _SCHEMA: Dict[str, Dict[str, Any]] = {
         # sequence-parallel attention flavor: ring | ulysses
         "sp_impl": (str, "ring"),
         # continuous-batching decode slots per replica (the north star
-        # needs 64-256; 64 measured best on one v5e chip, BENCH r2)
+        # needs 64-256; the best value is not measured on current code)
         "max_batch": (int, 64),
         "prefill_buckets": (list, [32, 128, 512]),
         "page_size": (int, 16),
@@ -518,9 +513,10 @@ def _load_file(path: str) -> Dict[str, Any]:
         with open(path) as f:
             obj = yaml.safe_load(f) or {}
     elif path.endswith(".toml"):
-        from distributed_inference_server_tpu.utils.compat import load_toml
+        import tomllib
 
-        obj = load_toml(path)
+        with open(path, "rb") as f:
+            obj = tomllib.load(f)
     else:
         raise ConfigError(f"unsupported config format: {path} (use .toml/.yaml)")
     if not isinstance(obj, dict):

@@ -1097,6 +1097,13 @@ class KvDataServer:
         self._stopping = True
         if self._sock is not None:
             try:
+                # close() alone does not wake a thread blocked in
+                # accept() on Linux — the join below then sat out its
+                # full timeout on every stop; shutdown() does
+                self._sock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
+            try:
                 self._sock.close()
             except OSError:
                 pass

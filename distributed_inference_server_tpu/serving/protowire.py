@@ -354,7 +354,7 @@ MESSAGES: Dict[str, Dict[int, _F]] = {
     "TeleDigest": {
         1: ("name", "string", "one"),
         2: ("epoch_s", "double", "one"),
-        3: ("epochs", "msg:TeleEpoch", "rep"),
+        3: ("ring", "msg:TeleEpoch", "rep"),
     },
     "TeleCounter": {
         1: ("name", "string", "one"),

@@ -42,6 +42,12 @@ from distributed_inference_server_tpu.serving.metrics import (
 
 logger = logging.getLogger(__name__)
 
+# Bound on engine construction + warm-up. On an empty compile cache the
+# warm-up compiles every serving program at full width before the runner
+# reports ready; 300 s read a slow-but-healthy cold start as "engine
+# failed to start". Generous on purpose: it only has to catch a hang.
+START_TIMEOUT_S = 1800.0
+
 
 class ResultSink(Protocol):
     """Receives a request's step outputs. Methods are called on the runner
@@ -206,7 +212,8 @@ class EngineRunner:
 
     # -- lifecycle ---------------------------------------------------------
 
-    def start(self, wait_ready: bool = True, timeout: float = 300.0) -> None:
+    def start(self, wait_ready: bool = True,
+              timeout: float = START_TIMEOUT_S) -> None:
         """Spawn the runner thread; optionally block until the engine is
         constructed (model loaded) and the runner reports ready
         (reference Req 7.2: worker reports ready before serving)."""
@@ -238,7 +245,8 @@ class EngineRunner:
         # anything still in flight will never complete — tell the clients
         self._fail_all("engine shut down before request completion")
 
-    def restart(self, wait_ready: bool = True, timeout: float = 300.0) -> None:
+    def restart(self, wait_ready: bool = True,
+                timeout: float = START_TIMEOUT_S) -> None:
         """Tear down and bring the engine back (worker self-restart,
         requirements.md:109)."""
         self.shutdown()
@@ -992,6 +1000,14 @@ class EngineRunner:
             loop=loop,
         )
 
+    def placement(self) -> Dict[str, object]:
+        """The engine's device ids and resolved attention pair
+        (``LLMEngine.placement``) for ``/health``; empty until the
+        engine is constructed. Local runners only — it does not ride the
+        fleet wire."""
+        eng = self._engine
+        return eng.placement() if eng is not None else {}
+
     # -- runner thread ----------------------------------------------------
 
     def _run(self, ready: threading.Event) -> None:
@@ -1003,6 +1019,7 @@ class EngineRunner:
                 self._engine.warmup()
             self._healthy = True
         except Exception as e:  # noqa: BLE001 — startup failure isolation
+            logger.exception("engine %s failed to start", self.engine_id)
             self._last_error = str(e)
             self._healthy = False
             ready.set()

@@ -156,7 +156,7 @@ class WindowedDigest:
                 "n": n,
                 "sum_us": total,
             })
-        return {"name": name, "epoch_s": self.epoch_s, "epochs": epochs}
+        return {"name": name, "epoch_s": self.epoch_s, "ring": epochs}
 
 
 # ---------------------------------------------------------------------------
@@ -187,7 +187,7 @@ def merge_digests(wires: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
         epoch_s = epoch_s or float(w.get("epoch_s", 0.0))
         if float(w.get("epoch_s", 0.0)) != epoch_s:
             continue  # foreign epoch geometry: see docstring
-        for ep in w.get("epochs", []):
+        for ep in w.get("ring", []):
             idx = int(ep.get("index", 0))
             slot = acc.get(idx)
             if slot is None:
@@ -204,7 +204,7 @@ def merge_digests(wires: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
         epochs.append({"index": idx, "buckets": buckets,
                        "counts": [counts[b] for b in buckets],
                        "n": n, "sum_us": total})
-    return {"name": name, "epoch_s": epoch_s, "epochs": epochs}
+    return {"name": name, "epoch_s": epoch_s, "ring": epochs}
 
 
 def window_stats(wire: Dict[str, Any], window_s: float,
@@ -220,7 +220,7 @@ def window_stats(wire: Dict[str, Any], window_s: float,
     counts: Dict[int, int] = {}
     n = 0
     total = 0
-    for ep in wire.get("epochs", []):
+    for ep in wire.get("ring", []):
         idx = int(ep.get("index", 0))
         if idx < first or idx > as_of_epoch:
             continue

@@ -128,6 +128,61 @@ class TestValidation:
 
         assert main(["--server-port", "0"]) != 0
 
+    def test_cli_refuses_an_unrequested_cpu_backend(self, capsys):
+        """jax falls back to the CPU silently when it finds no
+        accelerator; unless the CPU was asked for, main() must say so and
+        exit non-zero instead of serving at CPU speed."""
+        import jax
+
+        from distributed_inference_server_tpu.__main__ import main
+
+        assert jax.devices()[0].platform == "cpu"
+        requested = jax.config.jax_platforms
+        jax.config.update("jax_platforms", "")  # as if nothing was asked
+        try:
+            assert main([]) != 0
+        finally:
+            jax.config.update("jax_platforms", requested)
+        assert "no accelerator" in capsys.readouterr().err
+
+
+class TestCompileCache:
+    """One policy for every entry point (utils/compile_cache.py): the
+    directory is placed from outside, else it is the checkout's."""
+
+    @pytest.fixture()
+    def updates(self, monkeypatch):
+        import jax
+
+        seen = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: seen.__setitem__(k, v))
+        return seen
+
+    def test_env_variable_wins_and_no_directory_is_set_in_code(
+            self, monkeypatch, updates, tmp_path):
+        from distributed_inference_server_tpu.utils.compile_cache import (
+            setup_compile_cache,
+        )
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert setup_compile_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates
+
+    def test_default_is_the_checkout(self, monkeypatch, updates):
+        import os
+
+        from distributed_inference_server_tpu.utils.compile_cache import (
+            setup_compile_cache,
+        )
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        want = os.path.join(checkout, ".jax_cache")
+        assert setup_compile_cache() == want
+        assert updates["jax_compilation_cache_dir"] == want
+
 
 class TestHotReload:
     def test_hot_diff_only_reloadable_keys(self):

@@ -1221,7 +1221,7 @@ import os
 def f():
     ok = os.environ.get("DIS_TPU_SERVER__PORT")
     bad = os.environ.get("DIS_TPU_SERVER__PROT")
-    other = os.environ.get("DIS_TPU_PLATFORM")
+    other = os.environ.get("DIS_TPU_DEBUG_GATHER")
     return ok, bad, other
 """,
     })

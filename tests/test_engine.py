@@ -355,11 +355,13 @@ def test_auto_impl_probe_downgrades_gracefully(tiny_params):
     assert engine._probe_pallas() == (False, False)
 
 
-def test_auto_impl_prefill_demoted_to_opt_in(tiny_params, monkeypatch):
-    """VERDICT r4 #3 "win or demote": even when Mosaic accepts BOTH
-    kernels, auto serves prefill on XLA (the one silicon datapoint has
-    the prefill kernel at 0.66x XLA) unless DIS_TPU_PALLAS_PREFILL=1
-    opts back in for crossover sweeps. Decode keeps pallas-if-compiles."""
+def test_auto_impl_serves_each_kernel_the_probe_accepts(tiny_params,
+                                                        monkeypatch):
+    """On a TPU "auto" serves decode AND prefill on the Pallas kernels
+    wherever Mosaic accepts them, independently per kernel (the XLA
+    prefill cannot compile at the default serving geometry: its dense f32
+    score tensor alone is 2 x 8 GB) — and what it resolved to, with any
+    rejection, is reported by placement() for /health."""
     import jax as jax_mod
 
     from distributed_inference_server_tpu.engine.engine import LLMEngine
@@ -367,10 +369,18 @@ def test_auto_impl_prefill_demoted_to_opt_in(tiny_params, monkeypatch):
     monkeypatch.setattr(jax_mod, "default_backend", lambda: "tpu")
     monkeypatch.setattr(LLMEngine, "_probe_pallas",
                         lambda self: (True, True))
-    monkeypatch.delenv("DIS_TPU_PALLAS_PREFILL", raising=False)
-    assert make_engine(tiny_params)._resolved_impl() == ("pallas", "xla")
-    monkeypatch.setenv("DIS_TPU_PALLAS_PREFILL", "1")
     assert make_engine(tiny_params)._resolved_impl() == ("pallas", "pallas")
+
+    def reject_prefill(self):
+        self._probe_rejected["chunked-prefill"] = "Mosaic said no"
+        return True, False
+
+    monkeypatch.setattr(LLMEngine, "_probe_pallas", reject_prefill)
+    place = make_engine(tiny_params).placement()
+    assert place["attention"] == {"decode": "pallas", "prefill": "xla"}
+    assert place["attention_rejected"] == {
+        "chunked-prefill": "Mosaic said no"}
+    assert place["device_ids"] == [0]
 
 
 class TestWarmup:

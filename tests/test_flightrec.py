@@ -415,5 +415,5 @@ class TestSloVerdicts:
         rec = FlightRecorder(metrics=m)
         _drive_request(rec, tokens=16)
         wires = m.perf.wire_digests()
-        assert wires["tbt_ms"]["epochs"]
-        assert wires["queue_wait_ms"]["epochs"]
+        assert wires["tbt_ms"]["ring"]
+        assert wires["queue_wait_ms"]["ring"]

@@ -28,6 +28,37 @@ pytestmark = pytest.mark.skipif(
 )
 
 
+def test_failed_build_never_serves_a_library_left_on_disk(monkeypatch):
+    """The tier is built from the committed sources at first use or the
+    Python tier serves: when the build fails, a library some earlier
+    build left on disk (older than, or unrelated to, today's sources) is
+    NOT loaded."""
+    import os
+    import subprocess
+
+    assert os.path.exists(native._LIB_PATH)  # available() built it
+
+    def failing_make(cmd, **_kw):
+        raise subprocess.CalledProcessError(2, cmd)
+
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_build_failed", False)
+    monkeypatch.setattr(native.subprocess, "run", failing_make)
+    assert native._load() is None
+    assert not native.loaded() and not native.available()
+
+
+def test_loaded_library_is_not_older_than_its_sources():
+    import glob
+    import os
+
+    assert native.loaded()
+    built = os.stat(native._LIB_PATH).st_mtime
+    for src in glob.glob(os.path.join(native._DIR, "*.cpp")):
+        if os.path.basename(src) != "stress.cpp":  # not part of the .so
+            assert built >= os.stat(src).st_mtime, src
+
+
 def _req(i: int, prio: Priority, t: float):
     return QueuedRequest(id=f"r{i}", data=i, priority=prio, enqueued_at=t)
 

@@ -356,7 +356,7 @@ def _rand_telemetry(rng: random.Random) -> dict:
             "name": rng.choice(["ttft_ms", "tbt_ms", "step_ms.mixed",
                                 f"series_{d}"]),
             "epoch_s": rng.choice([1.0, 5.0, 30.0]),
-            "epochs": epochs,
+            "ring": epochs,
         })
     counters = [
         {"name": f"step.engine-{i}.prefill.tokens",

@@ -117,6 +117,9 @@ class TestRaggedKernelVsReference:
             (8, 2, 16, 2, 64, 2, [8, 0]),
             # MHA-ish KV=8 with a mid-size chunk mix
             (24, 5, 8, 8, 16, 3, [3, 1, 8, 1, 5]),
+            # KV*D wider than one 128-lane chunk (two head chunks): each
+            # grid step must DMA only its own chunk's lane window
+            (16, 3, 8, 4, 64, 3, [1, 1, 14]),
         ],
     )
     def test_seeded_geometries(self, S, Bm, H, KV, D, P, q_lens):

@@ -255,6 +255,14 @@ def test_health(server):
         body = await resp.json()
         assert body["status"] == "ok"
         assert body["accepting"] is True
+        # the device facts an outside checker (chip_smoke.py) relies on
+        assert body["platform"] == "cpu" and body["device_count"] == 8
+        assert body["device_kind"] and "compile_cache_dir" in body
+        assert isinstance(body["native_tier"], bool)
+        (engine,) = body["engines"]
+        assert engine["device_ids"] == [0]
+        assert engine["attention"] == {"decode": "xla", "prefill": "xla"}
+        assert engine["attention_rejected"] == {}
 
     _run(server, go)
 
@@ -675,3 +683,28 @@ class TestV1ParityTail:
             assert len((await ok.json())["choices"]) == 2
 
         _run(server, go)
+
+
+def test_four_replicas_hold_four_devices():
+    """``--server-num-engines 4`` through the real entry point: replica i
+    is pinned to device i (committed weights and pool), so /health shows
+    four engines on four distinct devices — not four replicas stacked on
+    ``jax.devices()[0]``. Runs on the 8-virtual-device CPU backend."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import chip_smoke
+
+    args = ["--server-num-engines", "4", "--model-model-name", "tiny",
+            "--model-dtype", "float32", "--engine-warmup-compile", "false",
+            "--engine-num-pages", "64", "--engine-page-size", "8",
+            "--engine-max-pages-per-seq", "16",
+            "--engine-prefill-buckets", "16"]
+    with chip_smoke.serving(args, platform="cpu",
+                            log_name="test_four_replicas.log") as (base, _):
+        health = chip_smoke.get_json(base, "/health")
+        assert health["device_count"] == 8
+        ids = [e["device_ids"] for e in health["engines"]]
+        assert sorted(ids) == [[0], [1], [2], [3]], ids

@@ -31,6 +31,10 @@ warm-replica death under cache-aware routing.
 Exit 0 = clean; exit 1 = violation (scenario + seed printed — commit it
 as a regression in tests/test_chaos.py, which runs fixed seeds of the
 same scenarios in tier-1).
+
+A CPU tool, by construction: ``JAX_PLATFORMS=cpu`` is pinned in this
+process's environment before jax is touched, so nothing it starts can
+inherit an unset platform and reach a TPU.
 """
 
 from __future__ import annotations

@@ -7,27 +7,38 @@ delta between this and bench.py's tok/s is, by construction, the cost of
 everything the engine adds (host loop, uploads, logprob reads, nucleus
 sampling, detok). hbm_probe.py bounds this number from above.
 
-Usage:
-    PYTHONPATH=... python tools/decode_probe.py [batch] [ctx] [block]
-Prints one JSON line per attention impl.
+Usage (on the chip, alone — one process per chip):
+    python tools/decode_probe.py [batch] [ctx] [block]
+Prints one JSON line per attention impl; exits 1 if either impl failed,
+2 when there is no accelerator (a CPU time is not a device number).
 """
 
 from __future__ import annotations
 
 import functools
 import json
+import os
 import sys
 import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 
 
 def main() -> int:
-    from _relay import relay_gate
+    from distributed_inference_server_tpu.utils.compile_cache import (
+        setup_compile_cache,
+    )
 
-    relay_gate()
+    setup_compile_cache()
+    if jax.devices()[0].platform == "cpu":
+        print(json.dumps({"probe": "decode_block",
+                          "error": "no accelerator: jax reports cpu"}),
+              flush=True)
+        return 2
     batch = int(sys.argv[1]) if len(sys.argv) > 1 else 64
     ctx = int(sys.argv[2]) if len(sys.argv) > 2 else 272
     block = int(sys.argv[3]) if len(sys.argv) > 3 else 64
@@ -35,7 +46,6 @@ def main() -> int:
     from distributed_inference_server_tpu.models import llama
     from distributed_inference_server_tpu.models.configs import get_config
 
-    import os
     cfg = get_config(os.environ.get("DP_MODEL", "llama-3.2-1b"))
     dtype = jnp.bfloat16
     params = llama.init_params(jax.random.PRNGKey(0), cfg, dtype=dtype)
@@ -76,6 +86,7 @@ def main() -> int:
     tokens = jnp.ones((batch,), jnp.int32)
     start = jnp.full((batch,), ctx, jnp.int32)
 
+    ok = True
     for impl in ("xla", "pallas"):
         try:
             t0 = time.perf_counter()
@@ -88,15 +99,15 @@ def main() -> int:
                 r = decode_block(params, pool_k, pool_v, tokens, start, impl)
             jax.block_until_ready(r)
             dt = (time.perf_counter() - t0) / reps
-            # DP_TRACE=1: capture a device trace of ONE extra block (the
-            # VERDICT r5 #2 evidence: name the residual per-step cost on
-            # the chip, op by op). Deliberately OUTSIDE the timed reps —
-            # step_ms stays comparable to the untraced r4 datapoints the
-            # probe exists to diagnose. Trace lands in traces/.
+            # DP_TRACE=1: capture a device trace of ONE extra block (to
+            # name the per-step cost on the chip, op by op). Deliberately
+            # OUTSIDE the timed reps, so step_ms is an untraced number.
+            # The trace lands under chiprun_out/, the one directory the
+            # chip tool brings back.
             if os.environ.get("DP_TRACE") == "1":
                 trace_dir = os.path.join(
-                    os.path.dirname(__file__), "..", "traces",
-                    f"decode_probe_{impl}_b{batch}_ctx{ctx}",
+                    os.path.dirname(__file__), "..", "chiprun_out",
+                    "traces", f"decode_probe_{impl}_b{batch}_ctx{ctx}",
                 )
                 jax.profiler.start_trace(trace_dir)
                 try:
@@ -116,10 +127,11 @@ def main() -> int:
                 "eff_hbm_gbps": round(weight_bytes / (step_ms / 1e3) / 1e9, 1),
             }), flush=True)
         except Exception as e:
+            ok = False
             print(json.dumps({"probe": "decode_block", "impl": impl,
                               "error": str(e).split("\n")[0][:200]}),
                   flush=True)
-    return 0
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

@@ -49,6 +49,12 @@ postmortem story, docs/OBSERVABILITY.md).
 
     JAX_PLATFORMS=cpu python tools/fleet_smoke.py
     python tools/fleet_smoke.py --worker --connect 127.0.0.1:PORT  # child
+
+A CPU tool, by construction: the parent pins ``JAX_PLATFORMS=cpu`` before
+it touches jax and every child is spawned with that pin in its
+environment. It must stay one — the parent holds jax while its children
+run, and a chip belongs to one process (the chip is reached only through
+``chip_smoke.py``, whose parent stays off jax).
 """
 
 from __future__ import annotations

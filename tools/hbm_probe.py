@@ -6,8 +6,10 @@ Two probes that bound what any decode step can do:
      step). GB/s = L*N*N*2 / t_step.
   2. big matmul: one [M,N]x[N,N] bf16 matmul — MXU TFLOP/s.
 
-Usage: PYTHONPATH=... python tools/hbm_probe.py [batch]
-Prints one JSON line per probe.
+Usage (on the chip, alone — one process per chip):
+    python tools/hbm_probe.py [batch]
+Prints one JSON line per probe; exits 2 when there is no accelerator (a
+CPU time is not a device number).
 """
 
 from __future__ import annotations
@@ -17,14 +19,23 @@ import os
 import sys
 import time
 
-import jax
-import jax.numpy as jnp
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 
 
 def main() -> int:
-    from _relay import relay_gate
+    from distributed_inference_server_tpu.utils.compile_cache import (
+        setup_compile_cache,
+    )
 
-    relay_gate()
+    setup_compile_cache()
+    if jax.devices()[0].platform == "cpu":
+        print(json.dumps({"probe": "hbm",
+                          "error": "no accelerator: jax reports cpu"}),
+              flush=True)
+        return 2
     batch = int(sys.argv[1]) if len(sys.argv) > 1 else 64
     N = int(os.environ.get("HP_N", "4096"))
     L = int(os.environ.get("HP_L", "16"))  # 16 * 4096*4096*2B = 512 MiB
